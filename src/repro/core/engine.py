@@ -448,6 +448,7 @@ class ESEngine:
             "w_mean": jnp.mean(w),
             "w_max": jnp.max(w),
             "scored": jnp.ones((), jnp.float32),
+            "sel_ids": idx,
         }
         new_params, new_opt, new_err = self._optim(state, grads, metrics)
         return dataclasses.replace(state, params=new_params, opt=new_opt,
@@ -493,6 +494,7 @@ class ESEngine:
             "w_max": jnp.max(w),
             "scored": do_score.astype(jnp.float32),
             "cad_period": cad.period.astype(jnp.float32),
+            "sel_ids": idx,
         }
         new_params, new_opt, new_err = self._optim(state, grads, metrics)
         return dataclasses.replace(state, params=new_params, opt=new_opt,

@@ -48,8 +48,7 @@ from typing import List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 
 @jax.tree_util.register_dataclass
@@ -85,6 +84,16 @@ class ScoreSharding:
     axes: Tuple[str, ...] = ("data",)
     n_global: Optional[int] = None   # logical store rows (None: local == global)
     offset: int = 0                  # first global row owned by this process
+
+    def __post_init__(self):
+        # the routed shard_map ops assume Auto axes: on an Explicit mesh
+        # (``jax.make_mesh``'s default) the store's sharding would type
+        # the whole jitted step, and the replicated model's ops would no
+        # longer match the single-device run
+        auto = (AxisType.Auto,) * len(self.mesh.axis_names)
+        if tuple(self.mesh.axis_types) != auto:
+            object.__setattr__(self, "mesh", Mesh(
+                self.mesh.devices, self.mesh.axis_names, axis_types=auto))
 
     @property
     def n_shards(self) -> int:
@@ -416,11 +425,11 @@ class ShardedStore(ScoreStore):
                                          mode="drop"))
 
         sp = ss.spec()
-        s, w, seen = shard_map(body, mesh=ss.mesh,
-                               in_specs=(sp, sp, sp, P(), P()),
-                               out_specs=(sp, sp, sp), check_rep=False)(
-                                   scores.s, scores.w, scores.seen,
-                                   ids, losses)
+        s, w, seen = jax.shard_map(body, mesh=ss.mesh,
+                                   in_specs=(sp, sp, sp, P(), P()),
+                                   out_specs=(sp, sp, sp), check_vma=False)(
+                                       scores.s, scores.w, scores.seen,
+                                       ids, losses)
         return ESScores(s=s, w=w, seen=seen)
 
     def gather(self, scores, ids):
@@ -446,9 +455,9 @@ class ShardedStore(ScoreStore):
             return (jax.lax.psum(s_v, ss.axes), jax.lax.psum(w_v, ss.axes))
 
         sp = ss.spec()
-        s_v, w_v = shard_map(body, mesh=ss.mesh, in_specs=(sp, sp, P()),
-                             out_specs=(P(), P()), check_rep=False)(
-                                 scores.s, scores.w, ids)
+        s_v, w_v = jax.shard_map(body, mesh=ss.mesh, in_specs=(sp, sp, P()),
+                                 out_specs=(P(), P()), check_vma=False)(
+                                     scores.s, scores.w, ids)
         # only per-process rows need host completion; a process-spanning
         # mesh already psums over every shard inside the jitted op
         comm = self._comm() if self.is_process_local else None
@@ -494,8 +503,8 @@ class ShardedStore(ScoreStore):
             _, sel = jax.lax.top_k(cand_keys, k)
             return cand_ids[sel].astype(jnp.int32)
 
-        return shard_map(body, mesh=ss.mesh, in_specs=ss.spec(),
-                         out_specs=P(), check_rep=False)(weights)
+        return jax.shard_map(body, mesh=ss.mesh, in_specs=ss.spec(),
+                             out_specs=P(), check_vma=False)(weights)
 
     # -- host ops --------------------------------------------------------
     def _local_blocks(self, arr) -> Tuple[List[np.ndarray], List[int]]:
@@ -942,10 +951,10 @@ class QuantizedStore(ScoreStore):
 
         sp = ss.spec()
         spec_tree = jax.tree.map(lambda _: sp, qs)
-        return shard_map(body, mesh=ss.mesh,
-                         in_specs=(spec_tree, P(), P()),
-                         out_specs=spec_tree, check_rep=False)(
-                             qs, ids, losses)
+        return jax.shard_map(body, mesh=ss.mesh,
+                             in_specs=(spec_tree, P(), P()),
+                             out_specs=spec_tree, check_vma=False)(
+                                 qs, ids, losses)
 
     def gather(self, qs, ids):
         if not isinstance(self.inner, ShardedStore):
@@ -983,8 +992,8 @@ class QuantizedStore(ScoreStore):
 
         sp = ss.spec()
         spec_tree = jax.tree.map(lambda _: sp, qs)
-        s_v, w_v = shard_map(body, mesh=ss.mesh, in_specs=(spec_tree, P()),
-                             out_specs=(P(), P()), check_rep=False)(qs, ids)
+        s_v, w_v = jax.shard_map(body, mesh=ss.mesh, in_specs=(spec_tree, P()),
+                                 out_specs=(P(), P()), check_vma=False)(qs, ids)
         comm = ShardedStore._comm() if self.is_process_local else None
         if comm is not None:
             if self.wire:
@@ -1040,8 +1049,8 @@ class QuantizedStore(ScoreStore):
             gids = id_all.astype(jnp.int32) + src * n_local
             return gids[sel]
 
-        return shard_map(body, mesh=ss.mesh, in_specs=ss.spec(),
-                         out_specs=P(), check_rep=False)(weights)
+        return jax.shard_map(body, mesh=ss.mesh, in_specs=ss.spec(),
+                             out_specs=P(), check_vma=False)(weights)
 
     # -- host ops --------------------------------------------------------
     @staticmethod

@@ -3,7 +3,8 @@
 Each kernel ships three files: <name>.py (pl.pallas_call + BlockSpec
 tiling), ops.py (jitted wrapper + backend dispatch), ref.py (pure-jnp
 oracle).  On non-TPU backends the wrappers run interpret mode
-(correctness); tests sweep shapes/dtypes against the oracles.
+(correctness) — except score_update, whose store path takes the XLA
+scatter there; tests sweep shapes/dtypes against the oracles.
 """
 from .xent.ops import per_sample_xent_fused, per_token_xent_fused
 from .segsum.ops import per_segment_xent_fused, segment_sum_fused

@@ -1,7 +1,7 @@
 """Jitted wrapper with backend + shard dispatch for the fused score update.
 
 On TPU the fused Pallas kernel replaces the three XLA scatters with one
-in-place VMEM pass.  Off-TPU there is no compiled Pallas path and the
+in-place pass over the touched HBM tiles.  Off-TPU there is no compiled Pallas path and the
 interpret-mode emulation of the serial update loop is an order of magnitude
 SLOWER than the scatters it fuses, so the store backends fall back to the
 pure-JAX scatter instead; interpret mode must be requested explicitly

@@ -46,6 +46,7 @@ from ..distributed.fault_tolerance import PreemptionHandler, StragglerMonitor
 from ..models.layers import ShardCtx
 from ..optim.adamw import OptConfig
 from ..optim.schedule import get_schedule
+from .compile_cache import use_compile_cache
 from .inputs import host_batch_placer
 
 
@@ -271,21 +272,18 @@ class Trainer:
         mesh — and the store — spans hosts).
 
         Flag-gated (``--shard-scores``); replicated remains the default.
-        Falls back to replicated (with a warning) when there is nothing to
-        shard over or the store does not divide evenly.
+        Raises when the flag cannot be honoured (one device, or a store
+        that does not divide evenly), so a run never reports a sharded
+        store it does not have.
         """
-        import warnings
         n_dev = len(jax.devices())
         if n_dev < 2:
-            warnings.warn("--shard-scores: single device, store stays "
-                          "replicated", stacklevel=2)
-            return None
+            raise ValueError("--shard-scores needs more than one device; "
+                             f"this run has {n_dev}")
         n = self.n_train
         if n % n_dev != 0:
-            warnings.warn(f"--shard-scores: n_train={n} not divisible by "
-                          f"{n_dev} devices, store stays replicated",
-                          stacklevel=2)
-            return None
+            raise ValueError(f"--shard-scores: n_train={n} is not divisible "
+                             f"by the {n_dev} devices")
         from ..distributed.sharding import score_store_sharding
         return score_store_sharding(jax.make_mesh((n_dev,), ("data",)))
 
@@ -450,6 +448,9 @@ class Trainer:
                # before this epoch; see prune_events for the gate decision)
                "epochs_since_prune": self.epochs_since_prune,
                "step_time": dur}
+        if "sel_ids" in m:
+            # meta-batch rows the step trained on (batch-level selection)
+            rec["sel_ids"] = np.asarray(m["sel_ids"]).tolist()
         self.metrics_log.append(rec)
         if self.ckpt and self.global_step % self.tc.ckpt_every_steps == 0:
             self._checkpoint(epoch)
@@ -589,6 +590,7 @@ class Trainer:
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3-8b", choices=list_archs())
     ap.add_argument("--smoke", action="store_true", default=True)

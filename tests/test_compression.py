@@ -55,16 +55,15 @@ def test_compressed_allreduce_multidevice_subprocess():
         import sys; sys.path.insert(0, "src")
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.distributed.compression import _compressed_mean_1d
         import functools
         mesh = jax.make_mesh((8,), ("data",))
         rng = np.random.default_rng(0)
         locals_ = rng.normal(size=(8, 64)).astype(np.float32)
-        f = shard_map(functools.partial(_compressed_mean_1d,
-                                        axis_name="data", axis_size=8),
-                      mesh=mesh, in_specs=P("data"), out_specs=P("data"),
-                      check_rep=False)
+        f = jax.shard_map(functools.partial(_compressed_mean_1d,
+                                            axis_name="data", axis_size=8),
+                          mesh=mesh, in_specs=P("data"), out_specs=P("data"),
+                          check_vma=False)
         # feed each device ITS row: stack along sharded axis
         out = np.asarray(f(jnp.asarray(locals_.reshape(-1))))
         want = locals_.mean(axis=0)
@@ -147,7 +146,6 @@ def test_compressed_psum_sum_multidevice_subprocess():
         import functools
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.distributed.compression import compressed_psum_sum
         mesh = jax.make_mesh((8,), ("data",))
         rng = np.random.default_rng(0)
@@ -156,10 +154,10 @@ def test_compressed_psum_sum_multidevice_subprocess():
         vals = rng.normal(size=512).astype(np.float32)
         locals_ = np.where(owner[None, :] == np.arange(8)[:, None],
                            vals[None, :], 0.0).astype(np.float32)
-        f = shard_map(functools.partial(compressed_psum_sum,
-                                        axis_name="data", axis_size=8),
-                      mesh=mesh, in_specs=P("data"), out_specs=P("data"),
-                      check_rep=False)
+        f = jax.shard_map(functools.partial(compressed_psum_sum,
+                                            axis_name="data", axis_size=8),
+                          mesh=mesh, in_specs=P("data"), out_specs=P("data"),
+                          check_vma=False)
         out = np.asarray(f(jnp.asarray(locals_.reshape(-1)))).reshape(8, -1)
         tol = np.abs(vals).max() / 127 * 4 + 1e-7
         for d in range(8):

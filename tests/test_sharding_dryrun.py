@@ -82,7 +82,8 @@ def test_mini_dryrun_8dev_subprocess():
         from repro.launch.hlo_cost import analyze
 
         cfg = get_smoke_config("llama3-8b")
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         ctx = make_ctx(cfg, mesh, "train")
         es = ESConfig(minibatch=4, n_train=64, seq_chunk=0)
         opt = OptConfig()
