@@ -43,6 +43,7 @@ single-process path.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import List, Optional, Tuple
 
 import jax
@@ -192,10 +193,11 @@ class ScoreStore:
     interface; whether the rows live replicated, sharded over a mesh, or
     split across processes is a backend detail.
 
-    Device ops (inside the jitted step):
+    Device ops (inside the jitted step, or eagerly between steps):
       ``update(scores, ids, losses, beta1, beta2, fused=...)``
       ``gather(scores, ids) -> (s[ids], w[ids])``
       ``select(key, weights, k) -> (k,) indices``  (Gumbel top-k)
+    Each backend compiles its device part through ``device_op``.
     Host ops (epoch boundary):
       ``prune_snapshot(scores)``  host-local row blocks + global offsets
       ``prune_epoch(...)``        set-level kept-set from the snapshot
@@ -205,6 +207,22 @@ class ScoreStore:
     """
 
     sharding: Optional[ScoreSharding] = None
+
+    @staticmethod
+    def device_op(*static: str):
+        """Decorator for a backend's device op: ``jax.jit`` with the store
+        and the named Python scalars (``beta1``, ``k``, ...) static.
+
+        Stores are frozen dataclasses, so equal stores hash equal and
+        share one compile per shape: an eager call between steps compiles
+        once instead of re-lowering its ``shard_map`` on every call, and
+        inside the trainer's jitted step the nested jit is inlined.  Both
+        sides of a replicated-vs-sharded parity check run compiled, so
+        they stay bit-comparable.  Host steps (the cross-process
+        completion of a per-process gather) stay outside, in Python.
+        """
+        return functools.partial(jax.jit,
+                                 static_argnames=("self",) + static)
 
     # -- device ops -----------------------------------------------------
     def init_leaf(self, n: int) -> ESScores:
@@ -286,6 +304,7 @@ class ReplicatedStore(ScoreStore):
     def init_leaf(self, n: int) -> ESScores:
         return init_scores(n)
 
+    @ScoreStore.device_op("beta1", "beta2", "fused", "interpret")
     def update(self, scores, ids, losses, beta1, beta2, *, fused=False,
                interpret=None):
         # interpret=None: kernel only where it compiles (TPU); an explicit
@@ -303,9 +322,11 @@ class ReplicatedStore(ScoreStore):
             return ESScores(s=s, w=w, seen=seen)
         return update_scores(scores, ids, losses, beta1, beta2)
 
+    @ScoreStore.device_op()
     def gather(self, scores, ids):
         return scores.s[ids], scores.w[ids]
 
+    @ScoreStore.device_op("k")
     def select(self, key, weights, k):
         from .selection import gumbel_topk_select
         return gumbel_topk_select(key, weights, k)
@@ -387,6 +408,7 @@ class ShardedStore(ScoreStore):
         return init_scores(n // nproc, self.sharding)
 
     # -- device ops ------------------------------------------------------
+    @ScoreStore.device_op("beta1", "beta2", "fused", "interpret")
     def update(self, scores, ids, losses, beta1, beta2, *, fused=False,
                interpret=None):
         ss = self.sharding
@@ -442,6 +464,22 @@ class ShardedStore(ScoreStore):
         range and the host collective completes the sum across processes
         (exact: every global row has exactly one owner).
         """
+        return self._complete_gather(*self._gather_shards(scores, ids))
+
+    def _complete_gather(self, s_v, w_v, compressed: bool = False):
+        """Host completion of a gather: only per-process rows need it (a
+        process-spanning mesh already psums over every shard inside the
+        jitted op); ``compressed`` ships the int8 wire payload."""
+        comm = self._comm() if self.is_process_local else None
+        if comm is None:
+            return s_v, w_v
+        reduce = (comm.allreduce_sum_compressed if compressed
+                  else comm.allreduce_sum)
+        return (jnp.asarray(reduce(np.asarray(s_v))),
+                jnp.asarray(reduce(np.asarray(w_v))))
+
+    @ScoreStore.device_op()
+    def _gather_shards(self, scores, ids):
         ss = self.sharding
         shard = ss.shard_size(scores.s.shape[0])
         base = ss.offset
@@ -455,16 +493,9 @@ class ShardedStore(ScoreStore):
             return (jax.lax.psum(s_v, ss.axes), jax.lax.psum(w_v, ss.axes))
 
         sp = ss.spec()
-        s_v, w_v = jax.shard_map(body, mesh=ss.mesh, in_specs=(sp, sp, P()),
-                                 out_specs=(P(), P()), check_vma=False)(
-                                     scores.s, scores.w, ids)
-        # only per-process rows need host completion; a process-spanning
-        # mesh already psums over every shard inside the jitted op
-        comm = self._comm() if self.is_process_local else None
-        if comm is not None:
-            s_v = jnp.asarray(comm.allreduce_sum(np.asarray(s_v)))
-            w_v = jnp.asarray(comm.allreduce_sum(np.asarray(w_v)))
-        return s_v, w_v
+        return jax.shard_map(body, mesh=ss.mesh, in_specs=(sp, sp, P()),
+                             out_specs=(P(), P()), check_vma=False)(
+                                 scores.s, scores.w, ids)
 
     def select(self, key, weights, k):
         """Gumbel top-k from device-local weight shards.
@@ -482,11 +513,14 @@ class ShardedStore(ScoreStore):
         gather's cross-process psum), so the replicated form IS the
         sharded result.
         """
-        from .selection import gumbel_topk_select
+        if self.is_process_local or weights.shape[0] % self.sharding.n_shards:
+            return ReplicatedStore().select(key, weights, k)
+        return self._select_shards(key, weights, k)
+
+    @ScoreStore.device_op("k")
+    def _select_shards(self, key, weights, k):
         B = weights.shape[0]
         ss = self.sharding
-        if self.is_process_local or B % ss.n_shards != 0:
-            return gumbel_topk_select(key, weights, k)
         n_local = B // ss.n_shards
         m = min(k, n_local)
 
@@ -710,12 +744,23 @@ def _q_init_leaf(rows: int, n_blocks: int, ring: int,
         err_w=jnp.zeros((ring,), jnp.float32))
 
 
+def _per_row(scales: jax.Array, blk: jax.Array) -> jax.Array:
+    """``scales[blk]``, kept a gather when the array holds one block.
+
+    A shard holding one scale block would have the lookup folded into a
+    broadcast, which XLA compiles with other multiply-add roundings than
+    the per-row gather of every other layout; the padding slot (never
+    indexed) keeps the compiled op, and so its bits, layout-invariant.
+    """
+    return jnp.pad(scales, (0, 1))[blk]
+
+
 def _q_gather_1d(q: jax.Array, scales: jax.Array, block: int,
                  err_rows: jax.Array, err_seq: jax.Array, err_val: jax.Array,
                  pos: jax.Array, gids: jax.Array) -> jax.Array:
     """Dequantized values for local rows ``pos``, corrected by the NEWEST
     ring residual whose global id matches ``gids`` (-1 never matches)."""
-    deq = q[pos].astype(jnp.float32) * scales[pos // block]
+    deq = q[pos].astype(jnp.float32) * _per_row(scales, pos // block)
     hit = err_rows[None, :] == gids[:, None]            # (B, R)
     stamped = jnp.where(hit, err_seq[None, :], 0)
     newest = jnp.argmax(stamped, axis=1)
@@ -748,8 +793,8 @@ def _q_grow_scales(qs: QuantizedScores, pos: jax.Array, mask: jax.Array,
     row_blk = jnp.arange(qs.s_q.shape[0], dtype=jnp.int32) // block
 
     def recode():
-        rs = (qs.s_scale / new_ss)[row_blk]      # <= 1: no clipping needed
-        rw = (qs.w_scale / new_ws)[row_blk]
+        rs = _per_row(qs.s_scale / new_ss, row_blk)   # <= 1: no clipping
+        rw = _per_row(qs.w_scale / new_ws, row_blk)
         return (jnp.round(qs.s_q.astype(jnp.float32) * rs).astype(jnp.int8),
                 jnp.round(qs.w_q.astype(jnp.float32) * rw).astype(jnp.int8))
 
@@ -797,8 +842,8 @@ def _q_apply_fixed(qs: QuantizedScores, pos: jax.Array, mask: jax.Array,
     unique-id batches)."""
     n = qs.s_q.shape[0]
     blk = pos // block
-    ssc = qs.s_scale[blk]
-    wsc = qs.w_scale[blk]
+    ssc = _per_row(qs.s_scale, blk)
+    wsc = _per_row(qs.w_scale, blk)
     s_prev = _q_gather_1d(qs.s_q, qs.s_scale, block, qs.err_rows,
                           qs.err_seq, qs.err_s, pos, gids)
     w_new = weights_from_prev(s_prev, losses, beta1)
@@ -833,6 +878,11 @@ def _q_update_local(qs: QuantizedScores, local_ids: jax.Array,
     pos = jnp.where(mask, local_ids, 0)
     mgids = jnp.where(mask, gids, -1)
     qs = _q_grow_scales(qs, pos, mask, mgids, losses, beta1, beta2, block)
+    # compiled, XLA would share the scatter path's ring lookups with this
+    # prologue and fuse (and round) it unlike the kernel path's: the
+    # barrier keeps the prologue, and so the scales, one program for both
+    qs, pos, mask, mgids, losses = jax.lax.optimization_barrier(
+        (qs, pos, mask, mgids, losses))
     slots, seqs = _q_ring_slots(qs.err_seq, mask)
     if use_kernel:
         from ..kernels.score_update.score_update import (
@@ -929,6 +979,7 @@ class QuantizedStore(ScoreStore):
         return qs
 
     # -- device ops ------------------------------------------------------
+    @ScoreStore.device_op("beta1", "beta2", "fused", "interpret")
     def update(self, qs, ids, losses, beta1, beta2, *, fused=False,
                interpret=None):
         losses = losses.astype(jnp.float32)
@@ -957,6 +1008,13 @@ class QuantizedStore(ScoreStore):
                                  qs, ids, losses)
 
     def gather(self, qs, ids):
+        s_v, w_v = self._gather_device(qs, ids)
+        if not self.is_process_local:
+            return s_v, w_v
+        return self.inner._complete_gather(s_v, w_v, compressed=self.wire)
+
+    @ScoreStore.device_op()
+    def _gather_device(self, qs, ids):
         if not isinstance(self.inner, ShardedStore):
             n = qs.s_q.shape[0]
             blk, _, _ = self._layout(n)
@@ -992,19 +1050,8 @@ class QuantizedStore(ScoreStore):
 
         sp = ss.spec()
         spec_tree = jax.tree.map(lambda _: sp, qs)
-        s_v, w_v = jax.shard_map(body, mesh=ss.mesh, in_specs=(spec_tree, P()),
-                                 out_specs=(P(), P()), check_vma=False)(qs, ids)
-        comm = ShardedStore._comm() if self.is_process_local else None
-        if comm is not None:
-            if self.wire:
-                s_v = jnp.asarray(
-                    comm.allreduce_sum_compressed(np.asarray(s_v)))
-                w_v = jnp.asarray(
-                    comm.allreduce_sum_compressed(np.asarray(w_v)))
-            else:
-                s_v = jnp.asarray(comm.allreduce_sum(np.asarray(s_v)))
-                w_v = jnp.asarray(comm.allreduce_sum(np.asarray(w_v)))
-        return s_v, w_v
+        return jax.shard_map(body, mesh=ss.mesh, in_specs=(spec_tree, P()),
+                             out_specs=(P(), P()), check_vma=False)(qs, ids)
 
     def select(self, key, weights, k):
         if not self.wire or not isinstance(self.inner, ShardedStore):
@@ -1019,12 +1066,17 @@ class QuantizedStore(ScoreStore):
         B/candidate.  Selection runs on the dequantized keys, so merges
         can differ from the exact path within one key-grid step (flagged
         mode; ``wire=False`` keeps the bit-exact merge)."""
-        from .selection import gumbel_topk_select
         ss = self.inner.sharding
         B = weights.shape[0]
         if (self.is_process_local or B % ss.n_shards != 0
                 or len(ss.axes) != 1 or B // ss.n_shards > 32767):
-            return gumbel_topk_select(key, weights, k)
+            return ReplicatedStore().select(key, weights, k)
+        return self._select_wire_shards(key, weights, k)
+
+    @ScoreStore.device_op("k")
+    def _select_wire_shards(self, key, weights, k):
+        ss = self.inner.sharding
+        B = weights.shape[0]
         n_local = B // ss.n_shards
         m = min(k, n_local)
         ax = ss.axes[0]

@@ -1,6 +1,9 @@
+import faulthandler
 import os
+import signal
 import subprocess
 import sys
+import threading
 
 # By default tests see the 1-device CPU backend (the dry-run alone uses 512
 # placeholder devices, in its own process).  The multi-device tier-1 job
@@ -22,6 +25,58 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))  # benchmarks 
 
 import pytest  # noqa: E402
 
+# Every test's wall-clock limit.  The subprocess harnesses below stop
+# their children well before it (``_SUBPROCESS_TIMEOUT_S``), so a stuck
+# child fails with its own output first.
+_TEST_LIMIT_S = 300
+_SUBPROCESS_TIMEOUT_S = 240
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_call(item):
+    """Fail a test that runs past ``_TEST_LIMIT_S``.
+
+    At the limit SIGALRM dumps every thread's stack with ``faulthandler``
+    into the test's stderr and fails the test, naming the limit.  Tests
+    run in the main thread (also in pytest-xdist workers), where SIGALRM
+    is delivered.  Where a SIGALRM handler is already installed, the test
+    gets only a ``faulthandler`` watchdog dump at the limit.  The
+    watchdog also backs up the alarm: a thread stuck in native code runs
+    no Python handler until it returns.
+    """
+    own_alarm = (threading.current_thread() is threading.main_thread()
+                 and signal.getsignal(signal.SIGALRM) in (
+                     signal.SIG_DFL, signal.SIG_IGN, None))
+    faulthandler.dump_traceback_later(_TEST_LIMIT_S + (60 if own_alarm else 0))
+    if own_alarm:
+        def on_alarm(signum, frame):
+            faulthandler.dump_traceback(all_threads=True)
+            pytest.fail(f"{item.nodeid} ran past the {_TEST_LIMIT_S} s "
+                        "per-test limit (tests/conftest.py)", pytrace=False)
+        previous = signal.signal(signal.SIGALRM, on_alarm)
+        signal.setitimer(signal.ITIMER_REAL, _TEST_LIMIT_S)
+    try:
+        return (yield)
+    finally:
+        if own_alarm:
+            signal.setitimer(signal.ITIMER_REAL, 0)
+            signal.signal(signal.SIGALRM, previous)
+        faulthandler.cancel_dump_traceback_later()
+
+
+def _timed_out(what: str, timeout: float, outputs) -> None:
+    """Fail with each child's output up to its ``timeout``."""
+    text = "\n".join(f"--- {name} ---\n{out}" for name, out in outputs)
+    pytest.fail(f"{what} ran past its {timeout} s timeout\n{text}",
+                pytrace=False)
+
+
+def _text(out) -> str:
+    # TimeoutExpired carries bytes even when the run asked for text
+    if isinstance(out, bytes):
+        return out.decode(errors="replace")
+    return out or ""
+
 
 @pytest.fixture(scope="session")
 def cpu_mesh8():
@@ -40,14 +95,16 @@ def cpu_mesh8():
     return Mesh(np.array(jax.devices()[:8]), ("data",))
 
 
-def run_multidevice(code: str, n_devices: int = 8, timeout: int = 900
+def run_multidevice(code: str, n_devices: int = 8,
+                    timeout: float = _SUBPROCESS_TIMEOUT_S
                     ) -> "subprocess.CompletedProcess":
     """Run a python snippet on ``n_devices`` forced CPU devices.
 
     Subprocess-safe: the parent process' jax backend is typically already
     initialized with one device and XLA_FLAGS can no longer change it, so
     the snippet gets a fresh interpreter with the flag exported before any
-    jax import.  The snippet must print ``OK`` on success.
+    jax import.  The snippet must print ``OK`` on success.  Past
+    ``timeout`` the snippet is killed and the test fails with its output.
     """
     import re
     env = dict(os.environ)
@@ -60,16 +117,22 @@ def run_multidevice(code: str, n_devices: int = 8, timeout: int = 900
         flags + f" --xla_force_host_platform_device_count"
         f"={n_devices}").strip()
     env.setdefault("JAX_PLATFORMS", "cpu")
-    r = subprocess.run(
-        [sys.executable, "-c", code], capture_output=True, text=True,
-        timeout=timeout, env=env,
-        cwd=os.path.join(os.path.dirname(__file__), ".."))
+    env["PYTHONUNBUFFERED"] = "1"      # a killed child keeps its output
+    try:
+        r = subprocess.run(
+            [sys.executable, "-c", code], capture_output=True, text=True,
+            timeout=timeout, env=env,
+            cwd=os.path.join(os.path.dirname(__file__), ".."))
+    except subprocess.TimeoutExpired as e:
+        _timed_out("run_multidevice snippet", timeout,
+                   [("stdout", _text(e.stdout)), ("stderr", _text(e.stderr))])
     assert "OK" in r.stdout, r.stdout + "\n" + r.stderr
     return r
 
 
 def run_cluster(code: str, n_procs: int = 2, n_devices_per_proc: int = 4,
-                timeout: int = 900, extra_env=None) -> list:
+                timeout: float = _SUBPROCESS_TIMEOUT_S, extra_env=None
+                ) -> list:
     """Run a python snippet on a local ``jax.distributed`` CPU cluster.
 
     Extends ``run_multidevice`` to real multi-PROCESS topology: ``n_procs``
@@ -79,10 +142,12 @@ def run_cluster(code: str, n_procs: int = 2, n_devices_per_proc: int = 4,
     (``distributed.hostcomm``) are live.  The snippet runs after
     ``jax.distributed.initialize`` on every process and must print ``OK``
     on each.  Returns the per-process stdouts (process order) so callers
-    can compare cross-topology digests.
+    can compare cross-topology digests.  Past ``timeout`` every process is
+    killed and the test fails with each one's output so far.
     """
     import socket
     import textwrap
+    import time
     sock = socket.socket()
     sock.bind(("127.0.0.1", 0))
     port = sock.getsockname()[1]
@@ -107,20 +172,26 @@ def run_cluster(code: str, n_procs: int = 2, n_devices_per_proc: int = 4,
             flags + f" --xla_force_host_platform_device_count"
             f"={n_devices_per_proc}").strip()
         env["JAX_PLATFORMS"] = "cpu"
+        env["PYTHONUNBUFFERED"] = "1"
         env["REPRO_PROC_ID"] = str(p)
         env.update(extra_env or {})
         procs.append(subprocess.Popen(
             [sys.executable, "-c", preamble + code],
             stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
             env=env, cwd=os.path.join(os.path.dirname(__file__), "..")))
+    deadline = time.monotonic() + timeout
     outs = []
-    for p, proc in enumerate(procs):
+    for proc in procs:
         try:
-            out, _ = proc.communicate(timeout=timeout)
+            out, _ = proc.communicate(
+                timeout=max(0.0, deadline - time.monotonic()))
         except subprocess.TimeoutExpired:
             for q in procs:
                 q.kill()
-            raise
+            rest = [q.communicate()[0] for q in procs[len(outs):]]
+            _timed_out(f"{n_procs}-process cluster", timeout,
+                       [(f"process {p}", _text(o))
+                        for p, o in enumerate(outs + rest)])
         outs.append(out)
     for p, out in enumerate(outs):
         assert "OK" in out, f"--- process {p} ---\n" + out

@@ -1,11 +1,10 @@
 """Gradient compression: quantization error bounds + error feedback."""
-import subprocess
-import sys
 import textwrap
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from conftest import run_multidevice
 
 from repro.distributed.compression import (quantize_int8, dequantize_int8,
                                            compress_decompress,
@@ -50,8 +49,6 @@ def test_compressed_allreduce_multidevice_subprocess():
     """Runs the shard_map int8 reduce on 8 placeholder devices — checks the
     compressed mean is within quantization tolerance of the true mean."""
     code = textwrap.dedent("""
-        import os
-        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import sys; sys.path.insert(0, "src")
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
@@ -73,10 +70,7 @@ def test_compressed_allreduce_multidevice_subprocess():
             assert err < np.abs(locals_).max() / 127 * 4, (d, err)
         print("OK")
     """)
-    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                       text=True, cwd=str(__import__("pathlib").Path(
-                           __file__).parent.parent))
-    assert "OK" in r.stdout, r.stdout + r.stderr
+    run_multidevice(code)
 
 
 # ---------------------------------------------------------------------------
@@ -140,8 +134,6 @@ def test_compressed_psum_sum_multidevice_subprocess():
     """The quantized store's wire=True routed-gather reduce: int8
     payloads, result within one grid step of the exact psum."""
     code = textwrap.dedent("""
-        import os
-        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import sys; sys.path.insert(0, "src")
         import functools
         import jax, jax.numpy as jnp, numpy as np
@@ -164,10 +156,7 @@ def test_compressed_psum_sum_multidevice_subprocess():
             assert np.abs(out[d] - vals).max() < tol, d
         print("OK")
     """)
-    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                       text=True, cwd=str(__import__("pathlib").Path(
-                           __file__).parent.parent))
-    assert "OK" in r.stdout, r.stdout + r.stderr
+    run_multidevice(code)
 
 
 def test_hostcomm_compressed_allreduce_roundtrip():

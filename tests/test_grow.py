@@ -58,7 +58,9 @@ def _touch(store, leaf, rng, n, B=16, rounds=2):
 def test_grow_parity_prefix_bitwise_tail_prior(seed, per_shard, grow_shards):
     """For any update stream then any (shard-divisible) growth: both
     backends bitwise-preserve the pre-grow rows, initialise the new tail
-    at 1/n_total with seen == 0, and stay bit-equal to each other."""
+    at 1/n_total with seen == 0, and stay bit-equal to each other.  Run
+    time: the store ops compile once per drawn size, before and after
+    the grow."""
     rep_store, shd_store = _stores()
     D = jax.device_count()
     n = per_shard * D
@@ -99,7 +101,8 @@ def test_grow_quantized_parity_and_prior(seed, per_shard, grow_mult):
     """Quantized growth: codes/scales/ring grow consistently on both
     placements — grown sharded-quant stays bit-equal to grown
     replicated-quant, old codes are bitwise-preserved, and the new tail
-    dequantizes to the 1/n' prior."""
+    dequantizes to the 1/n' prior.  Run time: the quantized ops of both
+    placements compile once per drawn size, before and after the grow."""
     D = jax.device_count()
     n = per_shard * D * 2
     n_new = per_shard * D * 2 * grow_mult

@@ -20,7 +20,10 @@ BIT-IDENTICAL to the single-process 8-device mesh run on the same seed:
     single-process checkpoint restores into the 2-process run.
 
 The shared id/loss stream is seeded, so the parent compares digests
-across topologies without moving arrays between them.
+across topologies without moving arrays between them.  The replicated
+reference is the compiled ``ReplicatedStore``, like the backends it
+anchors: compiled XLA contracts multiply-adds, op-by-op dispatch does
+not, so only like-compiled sides can be bit-compared.
 """
 import textwrap
 
@@ -37,10 +40,10 @@ _WORKLOAD = textwrap.dedent("""
     import numpy as np
     import jax, jax.numpy as jnp
     from repro.core.pruning import prune_epoch
-    from repro.core.scores import init_scores, update_scores
-    from repro.core.selection import gumbel_topk_select
+    from repro.core.scores import ReplicatedStore, init_scores
 
     N, B, T = 64, 16, 5
+    REF = ReplicatedStore()    # the reference, compiled like the store ops
 
     def stream():
         rng = np.random.default_rng(0)
@@ -69,12 +72,12 @@ _WORKLOAD = textwrap.dedent("""
             np.testing.assert_array_equal(np.asarray(w_g),
                                           np.asarray(ref.w[ids]))
             scores = store.update(scores, ids, losses, 0.2, 0.9)
-            ref = update_scores(ref, ids, losses, 0.2, 0.9)
+            ref = REF.update(ref, ids, losses, 0.2, 0.9)
         key = jax.random.PRNGKey(7)
         wsel = store.gather(scores, jnp.arange(B, dtype=jnp.int32))[1]
         sel = store.select(key, wsel, 6)
         np.testing.assert_array_equal(
-            np.asarray(sel), np.asarray(gumbel_topk_select(key, wsel, 6)))
+            np.asarray(sel), np.asarray(REF.select(key, wsel, 6)))
         kept_digs = []
         prev = prev_losses()
         for method in ("eswp", "infobatch", "ucb", "ka", "random"):
@@ -178,7 +181,7 @@ def test_cluster_checkpoint_restores_across_process_counts(tmp_path):
     2-process partitioned restore."""
     import jax.numpy as jnp
     from repro.checkpoint.checkpointer import Checkpointer
-    from repro.core.scores import init_scores, update_scores
+    from repro.core.scores import ReplicatedStore, init_scores
 
     # the single-process truth of the same workload
     def run_ref():
@@ -187,7 +190,7 @@ def test_cluster_checkpoint_restores_across_process_counts(tmp_path):
         for _ in range(5):
             ids = jnp.asarray(rng.choice(64, 16, replace=False), jnp.int32)
             losses = jnp.asarray(rng.uniform(0.1, 3.0, 16), jnp.float32)
-            ref = update_scores(ref, ids, losses, 0.2, 0.9)
+            ref = ReplicatedStore().update(ref, ids, losses, 0.2, 0.9)
         return ref
 
     # 1) replicated single-process checkpoint for the cluster to restore

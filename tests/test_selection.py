@@ -9,13 +9,14 @@ except ModuleNotFoundError:          # hermetic env: deterministic shim
     from _hypothesis_fallback import given, settings, st
 
 from repro.core.selection import (gumbel_topk_select, topk_select,
-                                  uniform_select, select_minibatch,
-                                  selection_probs)
+                                  select_minibatch, selection_probs)
 
 
 @settings(max_examples=25, deadline=None)
 @given(st.integers(2, 64), st.integers(1, 64), st.integers(0, 2 ** 31 - 1))
 def test_selection_without_replacement(n, k, seed):
+    """Gumbel top-k returns k distinct in-range indices.  Run time: each
+    example draws a new (n, k), and the eager ops compile once per shape."""
     k = min(k, n)
     key = jax.random.PRNGKey(seed)
     w = jnp.abs(jax.random.normal(key, (n,))) + 0.01

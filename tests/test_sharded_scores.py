@@ -26,9 +26,12 @@ import jax.numpy as jnp  # noqa: E402
 
 from repro.core.pruning import (PruneSnapshot, prune_epoch,  # noqa: E402
                                 prune_epoch_snapshot)
-from repro.core.scores import (ScoreSharding, ShardedStore,  # noqa: E402
-                               init_scores, update_scores)
-from repro.core.selection import gumbel_topk_select  # noqa: E402
+from repro.core.scores import (ReplicatedStore, ScoreSharding,  # noqa: E402
+                               ShardedStore, init_scores, update_scores)
+
+# the replicated reference, compiled like the sharded ops it anchors
+# (compiled XLA contracts multiply-adds, op-by-op dispatch does not)
+_REF = ReplicatedStore()
 
 
 def _ss(mesh) -> ScoreSharding:
@@ -85,7 +88,7 @@ def test_update_and_gather_bit_parity(cpu_mesh8):
                                       np.asarray(rep.s[ids]))
         np.testing.assert_array_equal(np.asarray(w_g),
                                       np.asarray(rep.w[ids]))
-        rep = update_scores(rep, ids, losses, 0.2, 0.9)
+        rep = _REF.update(rep, ids, losses, 0.2, 0.9)
         shd = store.update(shd, ids, losses, 0.2, 0.9)
     for a, b in ((shd.s, rep.s), (shd.w, rep.w), (shd.seen, rep.seen)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
@@ -103,7 +106,7 @@ def test_fused_ops_dispatch_per_shard(cpu_mesh8):
     rng = np.random.default_rng(1)
     ids = jnp.asarray(rng.choice(n, B, replace=False), jnp.int32)
     losses = jnp.asarray(rng.uniform(0.1, 3.0, B), jnp.float32)
-    rep = update_scores(init_scores(n), ids, losses, 0.2, 0.9)
+    rep = _REF.update(init_scores(n), ids, losses, 0.2, 0.9)
     shd = update_scores_fused(init_scores(n, ss), ids, losses, 0.2, 0.9,
                               sharding=ss)
     np.testing.assert_array_equal(np.asarray(shd.s), np.asarray(rep.s))
@@ -150,7 +153,7 @@ def test_sharded_gumbel_topk_matches_replicated(cpu_mesh8):
         w = jnp.asarray(rng.uniform(0.01, 5.0, 32), jnp.float32)
         key = jax.random.PRNGKey(trial)
         np.testing.assert_array_equal(
-            np.asarray(gumbel_topk_select(key, w, 6)),
+            np.asarray(_REF.select(key, w, 6)),
             np.asarray(store.select(key, w, 6)))
 
 
@@ -441,3 +444,52 @@ def test_trainer_shard_scores_flag_subprocess():
         print("OK")
     """)
     run_multidevice(code)
+
+
+# ---------------------------------------------------------------------------
+# eager store ops compile once per shape
+# ---------------------------------------------------------------------------
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+@pytest.mark.parametrize("quantize", [False, True], ids=["f32", "int8"])
+def test_sharded_store_ops_compile_once(quantize):
+    """Called eagerly three times with one shape, each sharded store op
+    compiles on the first call only (counted on JAX's backend-compile
+    event), so a service that updates the store between steps pays no
+    compile per call."""
+    from repro.core.scores import make_store
+    mesh = jax.make_mesh((jax.device_count(),), ("data",))
+    store = make_store(_ss(mesh), quantize=quantize, block=8,
+                       residual_rows=64)
+    n = 16 * jax.device_count()
+    leaf = store.init_leaf(n)
+    ids = jnp.asarray([1, 5, 5, n + 2, -1, 3], jnp.int32)
+    gather_ids = jnp.asarray([1, 5, 3, n - 1], jnp.int32)
+    losses = jnp.linspace(0.1, 2.0, 6, dtype=jnp.float32)
+    key = jax.random.PRNGKey(0)
+    compiles = []
+
+    def on_event(event, secs, **kwargs):
+        if event == _COMPILE_EVENT:
+            compiles.append(secs)
+
+    jax.clear_caches()
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    counts = {"update": [], "gather": [], "select": []}
+    try:
+        for _ in range(3):
+            before = len(compiles)
+            leaf = jax.block_until_ready(
+                store.update(leaf, ids, losses, 0.2, 0.9))
+            counts["update"].append(len(compiles) - before)
+            before = len(compiles)
+            jax.block_until_ready(store.gather(leaf, gather_ids))
+            counts["gather"].append(len(compiles) - before)
+            before = len(compiles)
+            jax.block_until_ready(store.select(key, losses, 3))
+            counts["select"].append(len(compiles) - before)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+    assert counts == {op: [1, 0, 0] for op in counts}, counts

@@ -1,16 +1,11 @@
 """Distribution machinery on a small placeholder mesh (subprocess: the
 dry-run proper uses 512 devices; here 8 devices validate the same code
 paths quickly — sharding rules, lowering the ES step, HLO analysis)."""
-import json
-import subprocess
-import sys
 import textwrap
-from pathlib import Path
 
-import pytest
-
+from conftest import run_multidevice
 from repro.configs.registry import get_config
-from repro.distributed.sharding import make_rules, dp_axes
+from repro.distributed.sharding import make_rules
 
 
 class _FakeMesh:
@@ -63,13 +58,10 @@ def test_rules_moe_modes():
     assert r_g["expert"] is None and r_g["moe_mlp"] == "model"      # TP
 
 
-@pytest.mark.slow
 def test_mini_dryrun_8dev_subprocess():
     """Lower+compile the ES train step on a (2,4) placeholder mesh with a
     smoke config — the full 512-device dry-run machinery end to end."""
     code = textwrap.dedent("""
-        import os
-        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import sys; sys.path.insert(0, "src")
         import json
         import jax, jax.numpy as jnp
@@ -110,7 +102,4 @@ def test_mini_dryrun_8dev_subprocess():
         assert coll > 0, "TP model must communicate"
         print("OK", json.dumps({"flops": res["flops"], "coll": coll}))
     """)
-    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                       text=True, timeout=600,
-                       cwd=str(Path(__file__).parent.parent))
-    assert "OK" in r.stdout, r.stdout + "\n" + r.stderr
+    run_multidevice(code)

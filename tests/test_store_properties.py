@@ -43,7 +43,9 @@ def _assert_scores_equal(a, b):
 def test_update_gather_parity_duplicates_oob_partial(seed, per_shard, B):
     """Random id streams: duplicate ids in one batch, ids outside [0, n)
     (both backends drop them), and B of any size (incl. not divisible by
-    the shard count) must leave both stores bit-identical."""
+    the shard count) must leave both stores bit-identical.  Run time:
+    each example draws a new store size and batch size, and each gather
+    a new id count, so the store ops compile once per draw."""
     rep_store, shd_store = _stores()
     D = jax.device_count()
     n = per_shard * D
@@ -76,7 +78,8 @@ def test_select_parity_any_batch_size(seed, B):
     """Gumbel selection from the sharded backend == the replicated
     reference for every batch size — divisible batches go through the
     per-shard candidate merge, partial ones through the (bit-equal)
-    replicated form."""
+    replicated form.  Run time: each example draws a new (B, k), so
+    both selects compile once per draw."""
     rep_store, shd_store = _stores()
     rng = np.random.default_rng(seed)
     w = jnp.asarray(rng.uniform(0.01, 5.0, B), jnp.float32)
@@ -93,7 +96,8 @@ def test_select_parity_any_batch_size(seed, B):
 def test_prune_parity_from_backend_snapshots(seed, per_shard, method):
     """``ScoreStore.prune_epoch`` (snapshot + exact global reductions)
     returns the same kept-set, grad rescale and s-snapshot from both
-    backends — and matches the full-array ``prune_epoch`` reference."""
+    backends — and matches the full-array ``prune_epoch`` reference.
+    Run time: each example compiles both updates for its drawn size."""
     rep_store, shd_store = _stores()
     D = jax.device_count()
     n = per_shard * D * 4
@@ -144,7 +148,8 @@ def test_quantize_blocks_grid_point_idempotence(seed, nb, block):
     dequantize bit-identically (quant o dequant == identity on the grid).
     The property needs the scale to be recoverable, i.e. each block holds
     a full-range code — otherwise re-quantization legitimately picks a
-    tighter grid."""
+    tighter grid.  Run time: each example draws a new (nb, block), and
+    the eager quantize ops compile once per shape."""
     rng = np.random.default_rng(seed)
     q0 = rng.integers(-127, 128, size=(nb, block))
     q0[:, 0] = 127                        # pin the block max: amax/127 == s0
@@ -171,7 +176,8 @@ def test_quantize_blocks_scale_floor_on_zero():
 @given(st.integers(0, 2 ** 31 - 1), st.integers(2, 24))
 def test_quant_fresh_residual_bounded_by_half_scale(seed, B):
     """Right after an update (no intervening growth), every ring residual
-    obeys |e| <= scale/2 — requant rounds to the nearest grid point."""
+    obeys |e| <= scale/2 — requant rounds to the nearest grid point.
+    Run time: the update compiles once per drawn batch size."""
     n = 256
     store = make_store(None, quantize=True, block=64, residual_rows=512)
     qs = store.init_leaf(n)
@@ -195,7 +201,8 @@ def test_quant_update_gather_roundtrip_vs_f32(seed, B):
     """Shuffled, duplicate and out-of-range id streams: the quantized
     store's gathers track the f32 recursion within the EF bound
     (scale/2 geometric sum over the beta2 EMA), and out-of-range ids are
-    dropped exactly like the f32 backends."""
+    dropped exactly like the f32 backends.  Run time: the updates
+    compile once per drawn batch size."""
     from repro.core.scores import init_scores, update_scores
     n = 128
     store = make_store(None, quantize=True, block=32, residual_rows=1024)
@@ -230,7 +237,9 @@ def test_quant_update_gather_roundtrip_vs_f32(seed, B):
 def test_quant_sharded_parity_any_stream(seed, per_shard, B):
     """Quantized placement invariance under hypothesis streams (dups,
     oob, any B): sharded-quant leaves bit-equal replicated-quant with a
-    roomy ring."""
+    roomy ring.  Run time: each example draws a new store and batch
+    size, and each gather a new id count, so the quantized ops of both
+    placements compile once per draw."""
     D = jax.device_count()
     n = per_shard * D * 4
     mesh = jax.make_mesh((D,), ("data",))
