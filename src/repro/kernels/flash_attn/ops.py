@@ -1,4 +1,4 @@
-"""Jitted wrapper: GQA layout handling + backend dispatch for flash attn."""
+"""The model-facing entry point of the flash attention kernel."""
 from __future__ import annotations
 
 import jax
@@ -7,28 +7,18 @@ import jax.numpy as jnp
 from .flash_attn import flash_attention
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def gqa_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                        causal: bool = True, block_q: int = 128,
-                        block_k: int = 128,
+                        causal: bool = True, block: int | None = None,
                         interpret: bool | None = None) -> jax.Array:
-    """q: (B, S, H, hd); k/v: (B, S, K, hd) with H = G*K -> (B, S, H, hd).
+    """q: (B, S, H, hd); k/v: (B, S, K, hd), H = K*G -> (B, S, H, hd).
 
-    KV heads are broadcast across their G query-head group without
-    materializing a repeated copy per q head beyond the (BH, S, hd) layout
-    the kernel needs.
+    Query head h = k*G + g reads kv head k.  The kernel reads the model's
+    layout, a lane-dense slice of heads per grid step, so grouped kv heads
+    are repeated to H here (their gradients sum back through the repeat).
     """
     if interpret is None:
-        interpret = not _on_tpu()
-    B, S, H, hd = q.shape
-    K = k.shape[2]
-    G = H // K
-    qf = q.transpose(0, 2, 1, 3).reshape(B * H, S, hd)
-    kf = jnp.repeat(k.transpose(0, 2, 1, 3), G, axis=1).reshape(B * H, S, hd)
-    vf = jnp.repeat(v.transpose(0, 2, 1, 3), G, axis=1).reshape(B * H, S, hd)
-    of = flash_attention(qf, kf, vf, causal=causal, block_q=block_q,
-                         block_k=block_k, interpret=interpret)
-    return of.reshape(B, H, S, hd).transpose(0, 2, 1, 3)
+        interpret = jax.default_backend() != "tpu"
+    G = q.shape[2] // k.shape[2]
+    if G > 1:
+        k, v = jnp.repeat(k, G, axis=2), jnp.repeat(v, G, axis=2)
+    return flash_attention(q, k, v, causal, block, interpret)

@@ -1,14 +1,25 @@
-"""GQA attention: training/prefill (q-chunked, memory-efficient) and decode.
+"""GQA attention: training/prefill, cross attention and decode.
 
 Layouts
   q:        (B, S, H, hd)   grouped internally to (B, S, K, G, hd), G = H/K
   k, v:     (B, S, K, hd)
   kv cache: (B, S_max, K, hd) per layer (stacked over layers by the caller)
 
-The q-chunked path never materializes the full (B, H, S, S) score tensor: it
-scans over query chunks, computing (B, K, G, qc, S) logits per step (flash
-style without online softmax — the full-K inner dimension keeps the math
-exact; remat keeps memory bounded).
+``mha`` has two paths, chosen from its input and the run's devices
+(``use_flash``):
+
+* the Pallas flash kernel (``kernels/flash_attn``), forward and backward,
+  on a run of one TPU chip for unpacked rows (no ``segment_ids`` or
+  ``positions``) without a mesh, when S and the heads fit its blocks: it
+  reads q/k/v in this layout, scores stay in VMEM and only the per-row
+  log-sum-exp is saved for the backward;
+* otherwise an XLA path that scans over query chunks of ``chunk_q``,
+  building the (B, K, G, qc, S) f32 scores of one chunk at a time (the
+  whole S x S block when ``chunk_q`` is 0 or does not divide S).  It serves
+  the CPU, packed rows, meshes, runs of several chips (a Mosaic kernel in a
+  jit over several devices must sit in a ``shard_map``, which ``mha`` does
+  not build) and odd shapes, and is the kernel's reference in the tests.
+  ``prefill_attn``, ``decode_attn`` and ``cross_attn`` always take XLA.
 """
 from __future__ import annotations
 
@@ -17,6 +28,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..kernels.flash_attn.flash_attn import block_sizes as flash_blocks
+from ..kernels.flash_attn.ops import gqa_flash_attention
 from .layers import Params, Axes, ShardCtx, winit, zeros, rope_angles, apply_rope
 
 NEG_INF = -1e30
@@ -113,6 +126,21 @@ def segment_causal_mask(q_pos: jax.Array, k_pos: jax.Array,
     return jnp.where(ok, 0.0, NEG_INF).astype(jnp.float32)
 
 
+def use_flash(backend: str, n_devices: int, seq_len: int, n_heads: int,
+              head_dim: int, ctx: ShardCtx,
+              positions: Optional[jax.Array] = None,
+              segment_ids: Optional[jax.Array] = None) -> bool:
+    """Whether ``mha`` takes the flash kernel: on a TPU run of one device
+    with no mesh (a jit over several devices, as a run with a row-sharded
+    score store or several hosts compiles, cannot partition the kernel),
+    for rows that are not packed (no ``segment_ids``, positions 0..S-1),
+    where the kernel has blocks (S a multiple of 128, an even number of
+    heads of 64 or 128)."""
+    return (backend == "tpu" and n_devices == 1 and ctx.mesh is None
+            and segment_ids is None and positions is None
+            and flash_blocks(seq_len, n_heads, head_dim) is not None)
+
+
 def mha(params: Params, x: jax.Array, *, n_heads: int, n_kv: int,
         head_dim: int, rope_theta: float, ctx: ShardCtx,
         chunk_q: int = 0, causal: bool = True,
@@ -123,9 +151,12 @@ def mha(params: Params, x: jax.Array, *, n_heads: int, n_kv: int,
     ``segment_ids`` (B, S) switches on packed-row masking: attention is
     causal *within* each segment and zero across segments/padding;
     ``positions`` must then be the per-segment (B, S) local positions so
-    RoPE restarts per document.
+    RoPE restarts per document.  ``chunk_q`` sets the XLA path's query
+    chunk; the flash kernel chooses its own blocks.
     """
     B, S, _ = x.shape
+    flash = use_flash(jax.default_backend(), jax.device_count(), S, n_heads,
+                      head_dim, ctx, positions, segment_ids)
     if positions is None:
         positions = jnp.arange(S)
     if segment_ids is not None:
@@ -135,6 +166,23 @@ def mha(params: Params, x: jax.Array, *, n_heads: int, n_kv: int,
     cos, sin = rope_angles(positions, head_dim, rope_theta)
     q = apply_rope(q, cos, sin)
     k = apply_rope(k, cos, sin)
+    if flash:
+        out = gqa_flash_attention(q, k, v, causal=causal)
+    else:
+        out = _chunked_attn(q, k, v, positions, segment_ids, chunk_q, causal)
+
+    out = ctx.constrain(out, "batch", None, "heads", None)
+    out = out.reshape(B, S, n_heads * head_dim)
+    return jnp.einsum("bsh,hd->bsd", out, params["wo"].astype(x.dtype))
+
+
+def _chunked_attn(q: jax.Array, k: jax.Array, v: jax.Array,
+                  positions: jax.Array, segment_ids: Optional[jax.Array],
+                  chunk_q: int, causal: bool) -> jax.Array:
+    """The XLA path of ``mha``: q (B, S, H, hd), k/v (B, S, K, hd) ->
+    (B, S, H, hd), one query chunk of ``chunk_q`` at a time."""
+    B, S, n_heads, head_dim = q.shape
+    n_kv = k.shape[2]
     G = n_heads // n_kv
     q = q.reshape(B, S, n_kv, G, head_dim)
 
@@ -162,18 +210,13 @@ def mha(params: Params, x: jax.Array, *, n_heads: int, n_kv: int,
             return None, _grouped_attn(q_blk, k, v, m)
 
         _, out = jax.lax.scan(body, None, chunked)
-        out = jnp.moveaxis(out, 0, 1).reshape(B, S, n_heads, head_dim)
+        return jnp.moveaxis(out, 0, 1).reshape(B, S, n_heads, head_dim)
+    if segment_ids is not None:
+        m: Optional[jax.Array] = segment_causal_mask(
+            positions, positions, segment_ids, segment_ids)
     else:
-        if segment_ids is not None:
-            m: Optional[jax.Array] = segment_causal_mask(
-                positions, positions, segment_ids, segment_ids)
-        else:
-            m = causal_mask(positions, positions) if causal else None
-        out = _grouped_attn(q, k, v, m).reshape(B, S, n_heads, head_dim)
-
-    out = ctx.constrain(out, "batch", None, "heads", None)
-    out = out.reshape(B, S, n_heads * head_dim)
-    return jnp.einsum("bsh,hd->bsd", out, params["wo"].astype(x.dtype))
+        m = causal_mask(positions, positions) if causal else None
+    return _grouped_attn(q, k, v, m).reshape(B, S, n_heads, head_dim)
 
 
 def cross_attn(params: Params, x: jax.Array, memory: jax.Array, *,

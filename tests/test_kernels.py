@@ -6,9 +6,10 @@ import pytest
 
 from repro.kernels.xent.ops import per_token_xent_fused, per_sample_xent_fused
 from repro.kernels.xent.ref import xent_ref
-from repro.kernels.flash_attn.flash_attn import flash_attention
+from repro.kernels.flash_attn.flash_attn import (flash_attention,
+                                                 flash_attention_fwd)
 from repro.kernels.flash_attn.ops import gqa_flash_attention
-from repro.kernels.flash_attn.ref import attention_ref
+from repro.kernels.flash_attn.ref import attention_ref, lse_ref
 from repro.kernels.score_update.score_update import fused_score_update
 from repro.kernels.score_update.ops import update_scores_fused
 from repro.kernels.score_update.ref import score_update_ref
@@ -73,29 +74,32 @@ def test_xent_per_sample_masking():
 # flash attention
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("S,hd,bq,bk", [(256, 64, 128, 128), (256, 64, 64, 128),
-                                        (128, 128, 64, 64)])
+@pytest.mark.parametrize("S,hd,block", [(256, 64, 128), (256, 64, 256),
+                                        (128, 128, 128)])
 @pytest.mark.parametrize("causal", [True, False])
-def test_flash_attention_matches_oracle(S, hd, bq, bk, causal):
+def test_flash_attention_matches_oracle(S, hd, block, causal):
     key = jax.random.PRNGKey(0)
     ks = jax.random.split(key, 3)
     q = jax.random.normal(ks[0], (2, S, hd))
     k = jax.random.normal(ks[1], (2, S, hd))
     v = jax.random.normal(ks[2], (2, S, hd))
-    got = flash_attention(q, k, v, block_q=bq, block_k=bk, causal=causal,
-                          interpret=True)
+    # the kernel reads (B, S, H, hd): these rows are the H = 2 heads of B = 1
+    heads = lambda x: x.transpose(1, 0, 2)[None]  # noqa: E731
+    got = flash_attention(heads(q), heads(k), heads(v), causal=causal,
+                          block=block, interpret=True)
     want = attention_ref(q, k, v, causal=causal)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    np.testing.assert_allclose(np.asarray(got[0].transpose(1, 0, 2)),
+                               np.asarray(want), atol=2e-5)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_attention_gqa_wrapper(dtype):
     key = jax.random.PRNGKey(1)
-    B, S, H, K, hd = 2, 128, 8, 2, 32
+    B, S, H, K, hd = 2, 128, 8, 2, 64
     q = jax.random.normal(key, (B, S, H, hd)).astype(dtype)
     k = jax.random.normal(key, (B, S, K, hd)).astype(dtype)
     v = jax.random.normal(key, (B, S, K, hd)).astype(dtype)
-    got = gqa_flash_attention(q, k, v, block_q=64, block_k=64, interpret=True)
+    got = gqa_flash_attention(q, k, v, block=128, interpret=True)
     # oracle: repeat kv
     G = H // K
     kr = jnp.repeat(k, G, axis=2).transpose(0, 2, 1, 3).reshape(B * H, S, hd)
@@ -105,6 +109,93 @@ def test_flash_attention_gqa_wrapper(dtype):
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), atol=tol)
+
+
+def _qkv(key, shape, dtype):
+    ks = jax.random.split(key, 4)
+    return [jax.random.normal(k, shape).astype(dtype) for k in ks]
+
+
+def _heads_ref(q, k, v, causal):
+    """``attention_ref`` on (B, S, H, hd), a head at a time."""
+    B, S, H, hd = q.shape
+    flat = lambda x: x.transpose(0, 2, 1, 3).reshape(B * H, S, hd)  # noqa
+    o = attention_ref(flat(q), flat(k), flat(v), causal)
+    return o.reshape(B, H, S, hd).transpose(0, 2, 1, 3)
+
+
+def _rel_err(got, want):
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    return float(np.abs(got - want).max() / np.abs(want).max())
+
+
+# relative to the largest entry: f32 inputs keep f32 MXU operands; bf16
+# inputs round q, k, v, p and dS to bf16 (8 bits of mantissa)
+REL_TOL = {jnp.float32: 1e-5, jnp.bfloat16: 3e-2}
+
+
+# two heads: at head 64 they share one 128-lane slab, at 128 one slab each
+@pytest.mark.parametrize("hd", [64, 128])
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_attention_fwd_and_lse(hd, causal, dtype):
+    """Forward output and the saved log-sum-exp; S of two blocks of 256,
+    so the diagonal tiles are worked in two strips each."""
+    q, k, v, _ = _qkv(jax.random.PRNGKey(2), (1, 512, 2, hd), dtype)
+    o, lse = flash_attention_fwd(q, k, v, causal=causal, block=256,
+                                 interpret=True)
+    assert o.dtype == dtype and o.shape == q.shape
+    assert _rel_err(o, _heads_ref(q, k, v, causal)) < REL_TOL[dtype]
+    flat = lambda x: x.transpose(0, 2, 1, 3).reshape(2, 512, hd)  # noqa
+    want = lse_ref(flat(q), flat(k), causal)                     # (H, S)
+    np.testing.assert_allclose(np.asarray(lse).reshape(2, 512),
+                               np.asarray(want), rtol=REL_TOL[dtype],
+                               atol=REL_TOL[dtype])
+
+
+@pytest.mark.parametrize("hd", [64, 128])
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_attention_grads(hd, causal, dtype):
+    """dq, dk, dv of the dQ and dK/dV kernels against ``jax.vjp`` of the
+    oracle; S = 512 is two blocks of 256, so tiles below, on (in two
+    strips) and above the diagonal all occur."""
+    q, k, v, do = _qkv(jax.random.PRNGKey(3), (1, 512, 2, hd), dtype)
+    f = lambda q, k, v: flash_attention(q, k, v, causal=causal,  # noqa: E731
+                                        block=256, interpret=True)
+    got = jax.vjp(f, q, k, v)[1](do)
+    f32 = [x.astype(jnp.float32) for x in (q, k, v, do)]
+    want = jax.vjp(lambda q, k, v: _heads_ref(q, k, v, causal),
+                   *f32[:3])[1](f32[3])
+    for name, g, w in zip("qkv", got, want):
+        assert g.dtype == dtype, name
+        assert _rel_err(g, w) < REL_TOL[dtype], name
+
+
+@pytest.mark.parametrize("B,H,K", [(2, 4, 2), (1, 4, 1)])
+def test_flash_attention_gqa_grads(B, H, K):
+    """Grouped kv heads repeated to the query heads: every kv head gathers
+    the gradients of its G query heads (G = 2, and G = 4 onto one kv
+    head)."""
+    S, hd = 256, 64
+    ks = jax.random.split(jax.random.PRNGKey(4), 4)
+    q = jax.random.normal(ks[0], (B, S, H, hd))
+    k = jax.random.normal(ks[1], (B, S, K, hd))
+    v = jax.random.normal(ks[2], (B, S, K, hd))
+    do = jax.random.normal(ks[3], (B, S, H, hd))
+
+    def oracle(q, k, v):
+        G = H // K
+        return _heads_ref(q, jnp.repeat(k, G, axis=2),
+                          jnp.repeat(v, G, axis=2), True)
+
+    f = lambda q, k, v: gqa_flash_attention(q, k, v, block=128,  # noqa
+                                            interpret=True)
+    got = jax.vjp(f, q, k, v)[1](do)
+    want = jax.vjp(oracle, q, k, v)[1](do)
+    for name, g, w in zip("qkv", got, want):
+        assert g.shape == w.shape, name
+        assert _rel_err(g, w) < 1e-5, name
 
 
 # ---------------------------------------------------------------------------

@@ -1,5 +1,6 @@
-"""The score_update kernels compile for a TPU v5e that is described, not
-attached: Mosaic's alignment and VMEM checks run here, without a chip.
+"""The score_update and flash attention kernels compile for a TPU v5e that
+is described, not attached: Mosaic's alignment and VMEM checks run here,
+without a chip.
 
 The topology is described inside a fixture (never at import time): only one
 process may load the TPU compiler library, and each pytest worker imports
@@ -17,6 +18,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.scores import ESScores, ScoreSharding, ShardedStore
+from repro.kernels.flash_attn.ops import gqa_flash_attention
 from repro.kernels.score_update.score_update import (
     fused_quant_score_update, fused_score_update)
 
@@ -102,3 +104,34 @@ def test_sharded_store_update_compiles(topo):
 
     _assert_kernel(update, scores, _sds((B,), jnp.int32, rep),
                    _sds((B,), jnp.float32, rep))
+
+
+def _assert_flash_compiles(shape, sharding):
+    """The forward and ``jax.grad`` through the dK/dV and dQ kernels."""
+    attn = functools.partial(gqa_flash_attention, interpret=False)
+    x = [_sds(shape, jnp.bfloat16, sharding)] * 3
+    _assert_kernel(attn, *x)
+
+    def loss(q, k, v):
+        return attn(q, k, v).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(*x).compile()
+    text = compiled.as_text()
+    for name in ("flash_attn_fwd", "flash_attn_dkv", "flash_attn_dq"):
+        assert name in text, name
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("B,hd", [(16, 64), (4, 64), (4, 128)])
+def test_flash_attention_compiles(one_chip, B, hd):
+    """The cells' shapes (S = 1024, 16 heads, one 1024 block): qwen1.5-0.5b's
+    scoring forward (B = 16) and training (B = 4), olmo-1b's training (head
+    128)."""
+    _assert_flash_compiles((B, 1024, 16, hd), one_chip)
+
+
+@pytest.mark.parametrize("hd", [64, 128])
+def test_flash_attention_compiles_three_blocks(one_chip, hd):
+    """S = 384: three 128-row blocks, so the causal kernels skip and clamp
+    the tiles above the diagonal, at the smallest block the kernel takes."""
+    _assert_flash_compiles((2, 384, 4, hd), one_chip)
