@@ -11,7 +11,10 @@ training), and beside them the kernel at its own blocks and
 forward+backward.  One JSON line per measurement: ms per
 call (host clock around ``block_until_ready`` of REPS calls, the median of
 3), and the share of the chip's bf16 peak that the leg's own matmuls reach
-on the causal half of the scores.  Needs a TPU; exits 2 without one.
+on the causal half of the scores.  Beside the kernel's legs, the score
+elements a call of each leg computes (``score_elements``) and the share of
+them that goes through the causal select (``select_share``, 1 at S = block,
+where the one tile is diagonal).  Needs a TPU; exits 2 without one.
 """
 from __future__ import annotations
 
@@ -99,7 +102,11 @@ def main() -> int:
         per_slab = fa.LANES // hd               # heads in one 128-lane slab
         for b, bh in itertools.product((256, 512, 1024),
                                        (per_slab, 2 * per_slab)):
-            rec = {"shape": shape, "path": "flash", "blocks": [b, bh]}
+            plan = fa._Plan(q, True, b, bh)
+            computed, selected = plan.scores()
+            rec = {"shape": shape, "path": "flash", "blocks": [b, bh],
+                   "score_elements": computed,
+                   "select_share": selected / computed}
             try:
                 legs = _legs(q, k, v, do, b, bh)
                 for name, (fn, a) in legs.items():

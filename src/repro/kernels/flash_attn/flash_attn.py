@@ -11,7 +11,10 @@ or 128 lanes, an even number of them: a grid step takes two heads
 (``block_sizes``), a lane-dense slice of H*hd.  Two heads of 64 share a
 128-lane slab; each head's products then run on the whole slab with the
 other head's lanes of one operand zeroed, which costs the MXU no more
-passes than a 64-wide head would, and its results land in its own lanes.
+passes than a 64-wide head would.  The operand zeroed is the one with a
+strip's rows (nr x 128), not the one with its columns (nc x 128, as many
+elements as the strip's scores); a product whose other lanes then hold the
+other head's sums is cut to the head's lanes as it is accumulated.
 
 Three ``pallas_call``s, named for the device trace:
 
@@ -139,36 +142,45 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, scale: float,
     q0, k0 = i * block, j * block
     heads = _heads(block_h, head_dim)
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
-        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
-        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
-
-    def update(r0, nr, c0, nc, masked):
+    def update(r0, nr, c0, nc, masked, first: bool):
+        """One online-softmax step; on a row's first kv tile (``first``)
+        it sets m, l and acc from the strip alone, with no rescale."""
         rows, cols = pl.ds(r0, nr), pl.ds(c0, nc)
         for t, slab, hm in heads:
-            v = _only(hm, v_ref[0, cols, slab])
-            s = jax.lax.dot_general(q_ref[0, rows, slab],
-                                    _only(hm, k_ref[0, cols, slab]), _NT,
+            s = jax.lax.dot_general(_only(hm, q_ref[0, rows, slab]),
+                                    k_ref[0, cols, slab], _NT,
                                     preferred_element_type=jnp.float32)
             s = s * scale
             if masked:
                 s = jnp.where(_mask(q0 + r0, k0 + c0, s.shape, False), s,
                               NEG_INF)
-            m_prev = m_scr[t, rows, :]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            corr = jnp.exp(m_prev - m_new)
-            l_scr[t, rows, :] = (corr * l_scr[t, rows, :]
-                                 + jnp.sum(p, axis=1, keepdims=True))
-            acc = acc_scr[rows, slab]
-            acc = acc * corr if hm is None else jnp.where(hm, acc * corr, acc)
-            acc_scr[rows, slab] = acc + jnp.dot(
-                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-            m_scr[t, rows, :] = m_new
+            m = jnp.max(s, axis=1, keepdims=True)
+            if not first:
+                m_prev = m_scr[t, rows, :]
+                m = jnp.maximum(m_prev, m)
+            p = jnp.exp(s - m)
+            l = jnp.sum(p, axis=1, keepdims=True)
+            v = v_ref[0, cols, slab]
+            acc = jnp.dot(p.astype(v.dtype), v,
+                          preferred_element_type=jnp.float32)
+            if not first:
+                corr = jnp.exp(m_prev - m)
+                l = corr * l_scr[t, rows, :] + l
+                acc = acc_scr[rows, slab] * corr + acc
+            if hm is not None:          # the slab's other heads keep theirs
+                acc = jnp.where(hm, acc, acc_scr[rows, slab])
+            acc_scr[rows, slab] = acc
+            l_scr[t, rows, :] = l
+            m_scr[t, rows, :] = m
 
-    _tiles(causal, block, q0, k0, update)
+    def run(first: bool):
+        _tiles(causal, block, q0, k0, functools.partial(update, first=first))
+
+    if n_q == 1:                        # every row meets one kv tile
+        run(True)
+    else:
+        pl.when(j == 0)(functools.partial(run, True))
+        pl.when(j > 0)(functools.partial(run, False))
 
     @pl.when(j == n_q - 1)
     def _finish():
@@ -205,20 +217,20 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref, di_ref,
     def update(r0, nr, c0, nc, masked):
         rows, cols = pl.ds(r0, nr), pl.ds(c0, nc)
         for t, slab, hm in heads:
-            k = _only(hm, k_ref[0, cols, slab])
-            s = jax.lax.dot_general(q_ref[0, rows, slab], k, _NT,
+            k = k_ref[0, cols, slab]
+            s = jax.lax.dot_general(_only(hm, q_ref[0, rows, slab]), k, _NT,
                                     preferred_element_type=jnp.float32)
             s = s * scale                                   # (rows, cols)
             if masked:
                 s = jnp.where(_mask(q0 + r0, k0 + c0, s.shape, False), s,
                               NEG_INF)
             p = jnp.exp(s - lse_scr[t, rows, :])
-            dp = jax.lax.dot_general(do_ref[0, rows, slab],
-                                     _only(hm, v_ref[0, cols, slab]), _NT,
+            dp = jax.lax.dot_general(_only(hm, do_ref[0, rows, slab]),
+                                     v_ref[0, cols, slab], _NT,
                                      preferred_element_type=jnp.float32)
             ds = p * (dp - di_scr[t, rows, :])
-            dq_scr[rows, slab] += jnp.dot(ds.astype(k.dtype), k,
-                                          preferred_element_type=jnp.float32)
+            dq_scr[rows, slab] += _only(hm, jnp.dot(
+                ds.astype(k.dtype), k, preferred_element_type=jnp.float32))
 
     _tiles(causal, block, q0, k0, update)
 
@@ -242,22 +254,21 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dk_ref, dv_ref,
     def update(r0, nr, c0, nc, masked):
         keys, qs = pl.ds(r0, nr), pl.ds(c0, nc)
         for t, slab, hm in heads:
-            q = _only(hm, q_ref[0, qs, slab])
-            do = _only(hm, do_ref[0, qs, slab])
-            s_t = jax.lax.dot_general(k_ref[0, keys, slab], q, _NT,
-                                      preferred_element_type=jnp.float32)
+            q, do = q_ref[0, qs, slab], do_ref[0, qs, slab]
+            s_t = jax.lax.dot_general(_only(hm, k_ref[0, keys, slab]), q,
+                                      _NT, preferred_element_type=jnp.float32)
             s_t = s_t * scale                               # (keys, qs)
             if masked:
                 s_t = jnp.where(_mask(q0 + c0, k0 + r0, s_t.shape, True),
                                 s_t, NEG_INF)
             p_t = jnp.exp(s_t - lse_ref[0, 0, pl.ds(t, 1), qs])
-            dv_scr[keys, slab] += jnp.dot(p_t.astype(do.dtype), do,
-                                          preferred_element_type=jnp.float32)
-            dp_t = jax.lax.dot_general(v_ref[0, keys, slab], do, _NT,
-                                       preferred_element_type=jnp.float32)
+            dv_scr[keys, slab] += _only(hm, jnp.dot(
+                p_t.astype(do.dtype), do, preferred_element_type=jnp.float32))
+            dp_t = jax.lax.dot_general(_only(hm, v_ref[0, keys, slab]), do,
+                                       _NT, preferred_element_type=jnp.float32)
             ds_t = p_t * (dp_t - di_ref[0, 0, pl.ds(t, 1), qs])
-            dk_scr[keys, slab] += jnp.dot(ds_t.astype(q.dtype), q,
-                                          preferred_element_type=jnp.float32)
+            dk_scr[keys, slab] += _only(hm, jnp.dot(
+                ds_t.astype(q.dtype), q, preferred_element_type=jnp.float32))
 
     _tiles(causal, block, q0, k0, update, kv_rows=True)
 
@@ -318,6 +329,20 @@ class _Plan:
     def rows(self):
         return jax.ShapeDtypeStruct((self.B, self.n_h, self.bh, self.S),
                                     jnp.float32)
+
+    def scores(self) -> Tuple[int, int]:
+        """(score elements a call of any leg computes, of them those that
+        go through the causal select): the tiles below the diagonal whole,
+        and a diagonal tile's strips as far as the diagonal, each masked
+        whole (``_tiles``)."""
+        if not self.causal:
+            return self.B * self.H * self.S * self.S, 0
+        n = self.b // STRIP
+        diagonal = STRIP * STRIP * n * (n + 1) // 2
+        below = self.n_q * (self.n_q - 1) // 2 * self.b * self.b
+        heads = self.B * self.H
+        return (heads * (below + self.n_q * diagonal),
+                heads * self.n_q * diagonal)
 
     def seen_k(self, i, j):
         """kv tile ``j``, or the last that q tile ``i`` sees."""

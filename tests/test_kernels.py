@@ -134,35 +134,48 @@ def _rel_err(got, want):
 REL_TOL = {jnp.float32: 1e-5, jnp.bfloat16: 3e-2}
 
 
+def _flash_cases(S, block):
+    """(dtype, causal, hd, S, block): S = 512 in blocks of 256 at every
+    dtype, causal or not, and head (ids without S), then the causal cases
+    at ``S`` and ``block`` (ids ending in their S)."""
+    cases = [pytest.param(dtype, causal, hd, 512, 256,
+                          id=f"{dtype.__name__}-{causal}-{hd}")
+             for dtype in (jnp.float32, jnp.bfloat16)
+             for causal in (True, False) for hd in (64, 128)]
+    return cases + [pytest.param(dtype, True, hd, S, block,
+                                 id=f"{dtype.__name__}-True-{hd}-S{S}")
+                    for dtype in (jnp.float32, jnp.bfloat16)
+                    for hd in (64, 128)]
+
+
 # two heads: at head 64 they share one 128-lane slab, at 128 one slab each
-@pytest.mark.parametrize("hd", [64, 128])
-@pytest.mark.parametrize("causal", [True, False])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_flash_attention_fwd_and_lse(hd, causal, dtype):
-    """Forward output and the saved log-sum-exp; S of two blocks of 256,
-    so the diagonal tiles are worked in two strips each."""
-    q, k, v, _ = _qkv(jax.random.PRNGKey(2), (1, 512, 2, hd), dtype)
-    o, lse = flash_attention_fwd(q, k, v, causal=causal, block=256,
+@pytest.mark.parametrize("dtype,causal,hd,S,block", _flash_cases(1024, 1024))
+def test_flash_attention_fwd_and_lse(dtype, causal, hd, S, block):
+    """Forward output and the saved log-sum-exp.  S of two blocks of 256
+    works each diagonal tile in two strips and a row's second kv tile
+    rescales what its first set; S = block = 1024, the cells' tiling, works
+    one tile in eight strips, each row's first and only kv tile."""
+    q, k, v, _ = _qkv(jax.random.PRNGKey(2), (1, S, 2, hd), dtype)
+    o, lse = flash_attention_fwd(q, k, v, causal=causal, block=block,
                                  interpret=True)
     assert o.dtype == dtype and o.shape == q.shape
     assert _rel_err(o, _heads_ref(q, k, v, causal)) < REL_TOL[dtype]
-    flat = lambda x: x.transpose(0, 2, 1, 3).reshape(2, 512, hd)  # noqa
+    flat = lambda x: x.transpose(0, 2, 1, 3).reshape(2, S, hd)  # noqa
     want = lse_ref(flat(q), flat(k), causal)                     # (H, S)
-    np.testing.assert_allclose(np.asarray(lse).reshape(2, 512),
+    np.testing.assert_allclose(np.asarray(lse).reshape(2, S),
                                np.asarray(want), rtol=REL_TOL[dtype],
                                atol=REL_TOL[dtype])
 
 
-@pytest.mark.parametrize("hd", [64, 128])
-@pytest.mark.parametrize("causal", [True, False])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_flash_attention_grads(hd, causal, dtype):
+@pytest.mark.parametrize("dtype,causal,hd,S,block", _flash_cases(512, 512))
+def test_flash_attention_grads(dtype, causal, hd, S, block):
     """dq, dk, dv of the dQ and dK/dV kernels against ``jax.vjp`` of the
-    oracle; S = 512 is two blocks of 256, so tiles below, on (in two
-    strips) and above the diagonal all occur."""
-    q, k, v, do = _qkv(jax.random.PRNGKey(3), (1, 512, 2, hd), dtype)
+    oracle.  S = 512 in two blocks of 256 has tiles below, on (in two
+    strips) and above the diagonal; in one block of 512, one tile in four
+    strips of 128 to 512 columns."""
+    q, k, v, do = _qkv(jax.random.PRNGKey(3), (1, S, 2, hd), dtype)
     f = lambda q, k, v: flash_attention(q, k, v, causal=causal,  # noqa: E731
-                                        block=256, interpret=True)
+                                        block=block, interpret=True)
     got = jax.vjp(f, q, k, v)[1](do)
     f32 = [x.astype(jnp.float32) for x in (q, k, v, do)]
     want = jax.vjp(lambda q, k, v: _heads_ref(q, k, v, causal),
@@ -170,6 +183,80 @@ def test_flash_attention_grads(hd, causal, dtype):
     for name, g, w in zip("qkv", got, want):
         assert g.dtype == dtype, name
         assert _rel_err(g, w) < REL_TOL[dtype], name
+
+
+def _strips_oracle(q, k, v, do):
+    """o, the lse (H, S), dq, dk, dv of causal attention over one tile of
+    S = n*128 rows holding two heads of 64 in one 128-lane slab, worked
+    strip by strip in the kernel's plain formulation: the other head's
+    lanes zeroed on the operand with the strip's columns (k and v; q and dO
+    for dK/dV), and the online softmax rescaled from its initial state."""
+    S, H, hd = q.shape[1:]
+    q, k, v, do = (x[0].reshape(S, H * hd) for x in (q, k, v, do))
+    f32, neg, scale = jnp.float32, -1e30, 1.0 / hd ** 0.5
+    nt = lambda a, b: jax.lax.dot_general(  # noqa: E731
+        a, b, (((1,), (1,)), ((), ())), preferred_element_type=f32)
+    nn = lambda a, b: jnp.dot(a.astype(b.dtype), b,  # noqa: E731
+                              preferred_element_type=f32)
+    keep = lambda nr, nc, r0: (jnp.arange(nr)[:, None] + r0  # noqa: E731
+                               >= jnp.arange(nc)[None])
+    lane = jnp.arange(H * hd)[None]
+    o, dq, dk, dv = (jnp.zeros((S, H * hd), f32) for _ in range(4))
+    lse = []
+    for h in range(H):
+        hm = lane // hd == h
+        only = lambda x: jnp.where(hm, x, jnp.zeros_like(x))  # noqa: E731
+        m_l = []
+        for r0 in range(0, S, 128):
+            rows, nc = slice(r0, r0 + 128), r0 + 128
+            s = jnp.where(keep(128, nc, r0), nt(q[rows], only(k[:nc]))
+                          * scale, neg)
+            m = jnp.maximum(jnp.full((128, 1), neg, f32),
+                            jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m)
+            corr = jnp.exp(neg - m)
+            l = corr * jnp.zeros((128, 1), f32) + p.sum(axis=1, keepdims=True)
+            acc = (jnp.where(hm, jnp.zeros((128, H * hd), f32) * corr, 0.0)
+                   + nn(p, only(v[:nc])))
+            o = o.at[rows].set(jnp.where(hm, acc / l, o[rows]))
+            m_l.append(m + jnp.log(l))
+        lse.append(jnp.concatenate(m_l)[:, 0])
+    o = o.astype(q.dtype)
+    for h in range(H):
+        hm = lane // hd == h
+        only = lambda x: jnp.where(hm, x, jnp.zeros_like(x))  # noqa: E731
+        di = jnp.sum(only(do.astype(f32) * o.astype(f32)), axis=1)
+        for r0 in range(0, S, 128):
+            rows, nc, qs = slice(r0, r0 + 128), r0 + 128, slice(r0, S)
+            s = jnp.where(keep(128, nc, r0), nt(q[rows], only(k[:nc]))
+                          * scale, neg)
+            p = jnp.exp(s - lse[h][rows, None])
+            ds = p * (nt(do[rows], only(v[:nc])) - di[rows, None])
+            dq = dq.at[rows].add(nn(ds, only(k[:nc])))
+            s_t = jnp.where(keep(S - r0, 128, 0).T,
+                            nt(k[rows], only(q[qs])) * scale, neg)
+            p_t = jnp.exp(s_t - lse[h][None, qs])
+            dv = dv.at[rows].add(nn(p_t, only(do[qs])))
+            ds_t = p_t * (nt(v[rows], only(do[qs])) - di[None, qs])
+            dk = dk.at[rows].add(nn(ds_t, only(q[qs])))
+    out = lambda x: x.astype(q.dtype).reshape(1, S, H, hd)  # noqa: E731
+    return out(o), jnp.stack(lse), out(dq * scale), out(dk * scale), out(dv)
+
+
+@pytest.mark.parametrize("S", [512, 1024])
+def test_flash_attention_exact_at_head_64(S):
+    """At head 64 with f32 inputs and one tile (S = block, the cells'
+    tiling), the kernel's o, lse, dq, dk and dv equal, bit for bit, the
+    plain formulation of ``_strips_oracle``: zeroing the other head's lanes
+    on the strip's rows and keeping the head's lanes of each product, and
+    setting the softmax state from a row's first kv tile, are exact."""
+    q, k, v, do = _qkv(jax.random.PRNGKey(5), (1, S, 2, 64), jnp.float32)
+    o, lse = flash_attention_fwd(q, k, v, block=S, interpret=True)
+    f = lambda q, k, v: flash_attention(q, k, v, True, S, True)  # noqa
+    got = [o, lse.reshape(2, S), *jax.vjp(f, q, k, v)[1](do)]
+    for name, g, w in zip(("o", "lse", "dq", "dk", "dv"), got,
+                          _strips_oracle(q, k, v, do)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w), name)
 
 
 @pytest.mark.parametrize("B,H,K", [(2, 4, 2), (1, 4, 1)])
